@@ -175,6 +175,15 @@ def _cell_fwd_bwd(wx, wh, b, first_in, h_in, c_in, dtop, dh, dc, *, cell, dt):
     )
 
 
+def _last_stage(outs, num_stages: int):
+    """The wavefront's output: its last stage's hidden states.  Where the
+    model axis spans devices, the hybrid plan's reshard to the batch layout
+    compiles onto this slice (GSPMD moves the phase boundary's collective to
+    the op that makes its operand), so the slice is named as the hand-off."""
+    with jax.named_scope("handoff"):
+        return outs[num_stages - 1]
+
+
 @functools.lru_cache(maxsize=32)
 def _scheduled_pipeline(mesh: Mesh, sched: PipelineSchedule, *, model_axis: str,
                         batch_axes: tuple, in_max: int, hidden: int, stage_kernel: str):
@@ -399,11 +408,11 @@ def _scheduled_pipeline(mesh: Mesh, sched: PipelineSchedule, *, model_axis: str,
     @jax.custom_vjp
     def run(stacked, x):
         outs = _run_fwd(stacked, x, save_boundaries=False)
-        return outs[NS - 1]
+        return _last_stage(outs, NS)
 
     def run_fwd(stacked, x):
         outs, lefts = _run_fwd(stacked, x, save_boundaries=True)
-        return outs[NS - 1], (stacked, x, lefts)
+        return _last_stage(outs, NS), (stacked, x, lefts)
 
     def run_bwd(res, dy):
         stacked, x, lefts = res
@@ -645,11 +654,11 @@ def _interleaved_pipeline(mesh: Mesh, sched: PipelineSchedule, *, model_axis: st
     @jax.custom_vjp
     def run(stacked, x):
         outs = _run_fwd(stacked, x, save_boundaries=False)
-        return outs[NS - 1]
+        return _last_stage(outs, NS)
 
     def run_fwd(stacked, x):
         outs, lefts = _run_fwd(stacked, x, save_boundaries=True)
-        return outs[NS - 1], (stacked, x, lefts)
+        return _last_stage(outs, NS), (stacked, x, lefts)
 
     def run_bwd(res, dy):
         stacked, x, lefts = res

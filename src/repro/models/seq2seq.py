@@ -116,19 +116,24 @@ def forward_no_input_feeding(
     """
     dt = resolve_dtype(cfg.dtype)
     run = backbone or (lambda ps, xs, rng: lstm.run_stacked_lstm(ps, xs, dropout_rng=rng, dropout=cfg.dropout)[0])
-    src_e = params["src_emb"]["table"].astype(dt)[batch.src]
-    tgt_e = params["tgt_emb"]["table"].astype(dt)[batch.tgt_in]
+    with jax.named_scope("embed"):
+        src_e = params["src_emb"]["table"].astype(dt)[batch.src]
+        tgt_e = params["tgt_emb"]["table"].astype(dt)[batch.tgt_in]
     rng_e = rng_d = None
     if dropout_rng is not None:
         rng_e, rng_d = jax.random.split(dropout_rng)
     # ---- phase 1: model-parallel backbone (all hidden states) ----------
-    S = run(params["encoder"], src_e, rng_e)  # [B, M, h]
-    H = run(params["decoder"], tgt_e, rng_d)  # [B, N, h]
+    with jax.named_scope("encoder"):
+        S = run(params["encoder"], src_e, rng_e)  # [B, M, h]
+    with jax.named_scope("decoder"):
+        H = run(params["decoder"], tgt_e, rng_d)  # [B, N, h]
     # ---- reshard boundary (the paper's hybrid hand-off) ----------------
-    S, H = phase_boundary(S), phase_boundary(H)
+    with jax.named_scope("handoff"):
+        S, H = phase_boundary(S), phase_boundary(H)
     # ---- phase 2: data-parallel attention-softmax ----------------------
-    _, logits = attention_softmax_head(params["head"], S, H, batch.src_mask, stage_kernel=stage_kernel)
-    loss, denom = softmax_cross_entropy(logits, batch.tgt_out, batch.tgt_mask)
+    with jax.named_scope("head"):
+        _, logits = attention_softmax_head(params["head"], S, H, batch.src_mask, stage_kernel=stage_kernel)
+        loss, denom = softmax_cross_entropy(logits, batch.tgt_out, batch.tgt_mask)
     return loss, {"logits": logits, "denom": denom}
 
 
@@ -146,14 +151,20 @@ def forward_input_feeding(
     dt = resolve_dtype(cfg.dtype)
     h = cfg.d_model
     B, N = batch.tgt_in.shape
-    src_e = params["src_emb"]["table"].astype(dt)[batch.src]
-    tgt_e = params["tgt_emb"]["table"].astype(dt)[batch.tgt_in]
-    S = lstm.run_stacked_lstm(params["encoder"], src_e, dropout_rng=dropout_rng, dropout=cfg.dropout)[0]
-    S = phase_boundary(S)
+    with jax.named_scope("embed"):
+        src_e = params["src_emb"]["table"].astype(dt)[batch.src]
+        tgt_e = params["tgt_emb"]["table"].astype(dt)[batch.tgt_in]
+    with jax.named_scope("encoder"):
+        S = lstm.run_stacked_lstm(params["encoder"], src_e, dropout_rng=dropout_rng, dropout=cfg.dropout)[0]
+    with jax.named_scope("handoff"):
+        S = phase_boundary(S)
     head = params["head"]
     dec = params["decoder"]
     states0 = [lstm.init_lstm_state(B, h) for _ in dec]
 
+    # scoped inside the body too: what the scan hoists out of its loop keeps
+    # the body's name stack, not the scan's
+    @jax.named_scope("decoder")
     def step(carry, emb_t):
         states, hc_prev = carry
         x = jnp.concatenate([emb_t, hc_prev.astype(dt)], axis=-1)
@@ -166,10 +177,12 @@ def forward_input_feeding(
         hc = Hc[:, 0]
         return (new_states, hc), hcur
 
-    (states, _), Hs = jax.lax.scan(step, (states0, jnp.zeros((B, h), dt)), tgt_e.swapaxes(0, 1))
-    H = Hs.swapaxes(0, 1)  # [B, N, h]
-    _, logits = attention_softmax_head(head, S, H, batch.src_mask, stage_kernel=stage_kernel)
-    loss, denom = softmax_cross_entropy(logits, batch.tgt_out, batch.tgt_mask)
+    with jax.named_scope("decoder"):
+        (states, _), Hs = jax.lax.scan(step, (states0, jnp.zeros((B, h), dt)), tgt_e.swapaxes(0, 1))
+        H = Hs.swapaxes(0, 1)  # [B, N, h]
+    with jax.named_scope("head"):
+        _, logits = attention_softmax_head(head, S, H, batch.src_mask, stage_kernel=stage_kernel)
+        loss, denom = softmax_cross_entropy(logits, batch.tgt_out, batch.tgt_mask)
     return loss, {"logits": logits, "denom": denom}
 
 

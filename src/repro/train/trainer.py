@@ -16,6 +16,7 @@ from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -302,9 +303,10 @@ def make_train_step(
     def train_step(state: TrainState, batch, lr_scale, rng):
         if not fp16:
             loss, extras, grads = grads_of(state.params, batch, rng)
-            grads, gnorm = clip_by_global_norm(grads, clip_norm)
-            updates, opt_state = optimizer.update(grads, state.opt_state, state.params, lr_scale)
-            params = apply_updates(state.params, updates)
+            with jax.named_scope("optimizer"):
+                grads, gnorm = clip_by_global_norm(grads, clip_norm)
+                updates, opt_state = optimizer.update(grads, state.opt_state, state.params, lr_scale)
+                params = apply_updates(state.params, updates)
             metrics = {"loss": loss, "grad_norm": gnorm, "tokens": extras["denom"]}
             if "aux" in extras:
                 metrics["moe_aux"] = extras["aux"]
@@ -317,23 +319,24 @@ def make_train_step(
         # doubles it.
         scale = state.scaling.scale
         loss, extras, grads = grads_of(state.params, batch, rng, scale)
-        finite = jnp.array(True)
-        for g in jax.tree.leaves(grads):
-            finite = jnp.logical_and(finite, jnp.all(jnp.isfinite(g)))
-        grads, gnorm = clip_by_global_norm(grads, clip_norm)
-        updates, opt_state_new = optimizer.update(grads, state.opt_state, state.params, lr_scale)
-        params_new = apply_updates(state.params, updates)
-        params = jax.tree.map(lambda n, o: jnp.where(finite, n, o), params_new, state.params)
-        opt_state = jax.tree.map(lambda n, o: jnp.where(finite, n, o), opt_state_new, state.opt_state)
-        good = jnp.where(finite, state.scaling.good_steps + 1, 0)
-        grow = good >= plan.loss_scale_growth
-        new_scale = jnp.where(
-            finite,
-            jnp.where(grow, scale * 2.0, scale),
-            jnp.maximum(scale * 0.5, 1.0),
-        )
-        good = jnp.where(grow, jnp.zeros_like(good), good)
-        scaling = LossScale(scale=new_scale, good_steps=good)
+        with jax.named_scope("optimizer"):
+            finite = jnp.array(True)
+            for g in jax.tree.leaves(grads):
+                finite = jnp.logical_and(finite, jnp.all(jnp.isfinite(g)))
+            grads, gnorm = clip_by_global_norm(grads, clip_norm)
+            updates, opt_state_new = optimizer.update(grads, state.opt_state, state.params, lr_scale)
+            params_new = apply_updates(state.params, updates)
+            params = jax.tree.map(lambda n, o: jnp.where(finite, n, o), params_new, state.params)
+            opt_state = jax.tree.map(lambda n, o: jnp.where(finite, n, o), opt_state_new, state.opt_state)
+            good = jnp.where(finite, state.scaling.good_steps + 1, 0)
+            grow = good >= plan.loss_scale_growth
+            new_scale = jnp.where(
+                finite,
+                jnp.where(grow, scale * 2.0, scale),
+                jnp.maximum(scale * 0.5, 1.0),
+            )
+            good = jnp.where(grow, jnp.zeros_like(good), good)
+            scaling = LossScale(scale=new_scale, good_steps=good)
         metrics = {
             "loss": loss,
             "grad_norm": gnorm,
@@ -360,6 +363,15 @@ def make_train_step(
     return train_step, sshard, batch_shardings
 
 
+def _bucket(batch: dict) -> dict:
+    """The batch's shape, as numbers on the ``trainer.dispatch`` span."""
+    out = {"rows": next(iter(batch.values())).shape[0]}
+    for key, name in (("src", "src_len"), ("tgt_in", "tgt_len")):
+        if key in batch:
+            out[name] = batch[key].shape[1]
+    return out
+
+
 class Trainer:
     """Minimal host loop: steps, periodic eval, plateau LR decay (paper)."""
 
@@ -384,18 +396,30 @@ class Trainer:
         return _sgd_v_fix(self.sshard, self.state.opt_state)
 
     def run(self, steps: int, log_every: int = 50, log=print):
+        """Runs ``steps`` steps.  Each step's host work is four profiler
+        spans, seen only while a trace is being taken: ``trainer.fetch``
+        (the next batch), ``trainer.put`` (its copies to the device, with
+        their ``bytes``), ``trainer.dispatch`` (the step's launch, with the
+        batch's ``rows`` and padded ``src_len``/``tgt_len``) and
+        ``trainer.sync`` (the read-back of the step's metrics)."""
         import time
 
         t0 = time.perf_counter()
         tokens = 0.0
         for i in range(steps):
-            batch = next(self.train_iter)
-            batch = {k: jnp.asarray(v) for k, v in batch.items()}
-            self.rng, sub = jax.random.split(self.rng)
-            self.state, metrics = self.step_fn(self.state, batch, self.lr_scale, sub)
-            tokens += float(metrics["tokens"])
-            if (i + 1) % log_every == 0:
-                loss = float(metrics["loss"])
+            with TraceAnnotation("trainer.fetch"):
+                batch = next(self.train_iter)
+            with TraceAnnotation("trainer.put", bytes=sum(v.nbytes for v in batch.values())):
+                batch = {k: jnp.asarray(v) for k, v in batch.items()}
+            with TraceAnnotation("trainer.dispatch", **_bucket(batch)):
+                self.rng, sub = jax.random.split(self.rng)
+                self.state, metrics = self.step_fn(self.state, batch, self.lr_scale, sub)
+            logging = (i + 1) % log_every == 0
+            with TraceAnnotation("trainer.sync"):
+                tokens += float(metrics["tokens"])
+                if logging:
+                    loss = float(metrics["loss"])
+            if logging:
                 dt = time.perf_counter() - t0
                 self.history.append({"step": i + 1, "loss": loss, "tok_per_s": tokens / dt})
                 log(f"step {i+1:5d}  loss {loss:.4f}  tok/s {tokens/dt:,.0f}  lr_scale {self.lr_scale:.3f}")
