@@ -239,14 +239,18 @@ def pipeline_activation_model(
     carry_bytes: int = 4,
     compute_dtype: Optional[str] = None,
     virtual_stages: int = 1,
+    stage_kernel: str = "jnp",
 ) -> dict:
     """Predicted peak stashed-activation bytes per pipeline stage for the
     seq2seq backbone's backward, per :class:`PipelineSchedule` kind.
 
-    One (stage, m, t) work unit stashes the per-layer recurrent carries its
-    cells consumed — ``2 * Lp * B_mb * H`` fp32 values (h_in + c_in; the
-    gates are recomputed analytically, never stashed) — so a stage's peak
-    is ``peak_stash_steps * unit_bytes``, a table property:
+    One (stage, m, t) work unit stashes what its cells produced in the
+    backward's recompute pass — each layer's h and c outputs and, for a
+    cell that exposes its gates (``stage_kernel="jnp"``), its four gate
+    pre-activations: ``(4 + 2) * Lp * B_mb * H`` fp32 values, or
+    ``2 * Lp * B_mb * H`` for the fused Pallas cell, whose backward
+    rebuilds the gates — live only while that side's backward runs.  A
+    stage's peak is ``peak_stash_steps * unit_bytes``, a table property:
 
     * ``gpipe``: ``k*S`` steps live at the fwd/bwd boundary — linear in
       ``micro_batches``, the memory wall this module's Table-3 throughput
@@ -265,8 +269,8 @@ def pipeline_activation_model(
     per-shard for a per-device number).
 
     ``compute_dtype`` makes the boundary-buffer bytes dtype-aware (the
-    hand-off vectors are saved in the activation dtype); the recurrent
-    carries stay fp32 — the executor keeps h/c in fp32 regardless.
+    hand-off vectors are saved in the activation dtype); the stash stays
+    fp32 — the executor keeps h/c and the gates in fp32 regardless.
 
     ``virtual_stages`` > 1 (interleaved): the table runs over ``v*NS``
     virtual stages whose work units each cover ``Lp/v`` layers, so the
@@ -281,8 +285,10 @@ def pipeline_activation_model(
     h = cfg.d_model
     lp = max(cfg.num_layers // num_stages, 1)
     b_mb = batch / micro_batches
-    # h_in + c_in per layer, fp32; one unit covers a CHUNK's layers
-    unit = 2 * (lp / chunks) * b_mb * h * carry_bytes
+    # h, c and (jnp cell) four gates per layer, fp32; one unit covers a
+    # CHUNK's layers
+    per_layer = 2 + (4 if stage_kernel == "jnp" else 0)
+    unit = per_layer * (lp / chunks) * b_mb * h * carry_bytes
     out = {"schedule": schedule, "unit_bytes": unit, "virtual_stages": chunks * num_stages}
     stash = bubble = live = 0
     boundary = 0.0

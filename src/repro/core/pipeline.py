@@ -35,11 +35,18 @@ autodiff's transpose of the forward scan (which stashes every one of the
   hand-off sequence — one [B, H] vector per token-step, ~6·Lp× smaller
   than the per-layer gate/state stash);
 * the backward runs over the schedule's backward groups
-  (:attr:`PipelineSchedule.bwd_group_starts`): per group it recomputes the
-  member microbatches' forward from the saved boundaries — stashing only
-  that group's ``g*S`` token-steps — then runs the mirrored backward
-  wavefront over the group with a per-tick ``ppermute`` carrying the
-  hand-off gradient down the stage chain.
+  (:attr:`PipelineSchedule.bwd_group_starts`): per group a recompute
+  pass (phase A) rebuilds the member microbatches' forward from the saved
+  boundaries, stashing that group's ``g*S`` token-steps — each layer's h
+  and c outputs and, where the cell exposes them, its four gate
+  pre-activations — then the mirrored backward wavefront (phase B) runs
+  over the group from the stash, with a per-tick ``ppermute`` carrying the
+  hand-off gradient down the stage chain.  Phase B computes no gate: with
+  the jnp cell a tick runs the 4·Lp GEMMs the gradient needs (dx, dh, dWx,
+  dWh per layer); the fused Pallas cell keeps its gates in VMEM, so its
+  backward rebuilds them (6·Lp).  The recompute pass starts only once the loss side
+  has sent its gradient back (:func:`_after_cotangent`), so its stash
+  never sits beside the head's activations.
 
 ``gpipe`` has one group of all k microbatches: peak stash ``k*S``
 token-steps per stage, exactly the table's (and the old autodiff's)
@@ -87,22 +94,25 @@ def stack_pipeline_params(layer_params: List[dict], num_stages: int):
     return {"wx": wx, "wh": wh, "b": b}, in_max
 
 
+def _pad_to(a, width: int):
+    """Zero-pad the last axis of ``a`` [B, n] up to ``width``."""
+    return jnp.pad(a, ((0, 0), (0, width - a.shape[-1]))) if a.shape[-1] < width else a
+
+
 def _make_cell(wx, wh, b, *, in_max: int, dt, stage_kernel: str):
-    """The per-tick stage cell: (l, x_in, h_prev, c_prev) -> (h, c), either
-    the plain einsum math or the fused Pallas kernel.  Shared by the
-    forward scan and the backward's recompute phase so the stashed carries
-    are bit-identical to the forward's."""
+    """The per-tick stage cell: (l, x_in, h_prev, c_prev) -> (h, c, gates)
+    with the plain einsum math, gates its fp32 pre-activations [B, 4, H];
+    or -> (h, c) with the fused Pallas kernel, which keeps its gates in
+    VMEM.  Shared by the forward scan and the backward's recompute pass,
+    so the stash is bit-identical to the forward's carries."""
+    from repro.kernels.lstm_cell.ops import lstm_cell_fused
 
     def cell(l, x_in, h_prev, c_prev):
-        if x_in.shape[-1] < in_max:
-            x_in = jnp.pad(x_in, ((0, 0), (0, in_max - x_in.shape[-1])))
         if stage_kernel != "jnp":
             # fused Pallas cell: gate GEMMs + state update in one kernel,
             # fed the stacked [in_max, 4, H] weights as-is (static gate
             # split).  h/c carries are fp32, so the kernel's outputs are
             # fp32 too.
-            from repro.kernels.lstm_cell.ops import lstm_cell_fused
-
             return lstm_cell_fused(x_in, h_prev, c_prev, wx[l], wh[l], b[l])
         gates = (
             jnp.einsum("bi,igh->bgh", x_in, wx[l].astype(dt))
@@ -112,52 +122,75 @@ def _make_cell(wx, wh, b, *, in_max: int, dt, stage_kernel: str):
         i, f, g, o = gates[:, 0], gates[:, 1], gates[:, 2], gates[:, 3]
         c = jax.nn.sigmoid(f) * c_prev + jax.nn.sigmoid(i) * jnp.tanh(g)
         h = jax.nn.sigmoid(o) * jnp.tanh(c)
-        return h, c
+        return h, c, gates
 
     return cell
 
 
 def _stage_sweep(cell, Lp, first_in, h_in, c_in, *, dt, in_max):
     """Run a stage's Lp cells upward from the given carries — THE one copy
-    of the layer sweep (inter-layer dtype cast included).  The forward
-    tick, the backward's group recompute, and the per-tick adjoint all
-    call this, so their linearization points can never drift.  Returns
-    (hs [Lp, B, H], cs [Lp, B, H], xs: per-layer [B, in_max] inputs)."""
+    of the layer sweep (inter-layer dtype cast included), run by the
+    forward tick and by the backward's recompute pass.  Returns (hs [Lp,
+    B, H], cs [Lp, B, H], stash): the stash holds one tuple per layer, its
+    h and c outputs and, where the cell returns them, its gates — what
+    the backward reads (:func:`_stashed_step`), one buffer each, so a
+    gate GEMM can write its output straight into its slot."""
     cur = first_in
-    hs, cs, xs = [], [], []
+    hs, cs, stash = [], [], []
     for l in range(Lp):
-        if cur.shape[-1] < in_max:
-            cur = jnp.pad(cur, ((0, 0), (0, in_max - cur.shape[-1])))
-        xs.append(cur)
-        hl, cl = cell(l, cur, h_in[l], c_in[l])
+        hl, cl, *gates = cell(l, _pad_to(cur, in_max), h_in[l], c_in[l])
         hs.append(hl)
         cs.append(cl)
+        stash.append((hl, cl, *gates))
         cur = hl.astype(dt)  # the forward's inter-layer cast
-    return jnp.stack(hs), jnp.stack(cs), xs
+    return jnp.stack(hs), jnp.stack(cs), tuple(stash)
 
 
-def _cell_fwd_bwd(wx, wh, b, first_in, h_in, c_in, dtop, dh, dc, *, cell, dt):
-    """Analytic backward of one stage-tick (all Lp layers) from the stashed
-    carries.  The per-layer inputs are recomputed through the SAME ``cell``
-    sweep as the forward (dtype casts and kernel path included, so the
-    linearization point matches the executed forward exactly) and
-    differentiated with the kernel package's shared analytic adjoint
-    (``kernels/lstm_cell/ops.py::lstm_cell_adjoint`` — one source of truth
-    for the cell math, fp32 gate recompute as in the fused kernel's vjp;
-    XLA CSEs the repeated gate GEMMs).  Returns
-    (dfirst_in, dh_prev, dc_prev, dwx, dwh, db)."""
-    from repro.kernels.lstm_cell.ops import lstm_cell_adjoint
+def _stashed_step(stash, j, t):
+    """Step ``j`` of the recompute pass's stash (:func:`_stage_sweep`'s,
+    each buffer [G, ...] by step) -> per layer (h_in, c_in, h, gates): the
+    carries that entered step j are step j-1's outputs, zero where a
+    microbatch starts (t == 0); gates None where the cell kept them."""
+    take = lambda a, i: jax.lax.dynamic_index_in_dim(a, i, axis=0, keepdims=False)
+    prev = jnp.maximum(j - 1, 0)
+    return [
+        (
+            jnp.where(t == 0, 0.0, take(h_all, prev)),
+            jnp.where(t == 0, 0.0, take(c_all, prev)),
+            take(h_all, j),
+            take(gates[0], j) if gates else None,
+        )
+        for h_all, c_all, *gates in stash
+    ]
+
+
+def _stage_bwd(wx, wh, b, first_in, layers, dtop, dh, dc, *, dt):
+    """Analytic backward of one stage-tick (all Lp layers) from the
+    recompute pass's stash, ``layers`` as :func:`_stashed_step` gives
+    them: the carries that entered the step, its h outputs (each layer
+    above 0 read the one below, cast as the forward cast it) and the
+    gates.  With gates it runs the kernel package's gate adjoint
+    (``kernels/lstm_cell/ops.py::lstm_cell_gate_adjoint``, the one source
+    of truth for the cell math): four GEMMs a layer.  A cell that keeps
+    its gates (the fused Pallas kernel) gets ``lstm_cell_adjoint``, which
+    rebuilds them first in fp32: six.
+    Returns (dfirst_in, dh_prev, dc_prev, dwx, dwh, db)."""
+    from repro.kernels.lstm_cell.ops import lstm_cell_adjoint, lstm_cell_gate_adjoint
 
     Lp, in_max = wx.shape[0], wx.shape[1]
     hidden = wh.shape[1]
-    _, _, xs = _stage_sweep(cell, Lp, first_in, h_in, c_in, dt=dt, in_max=in_max)
+    xs = [first_in] + [_pad_to(layers[l][2].astype(dt), in_max) for l in range(Lp - 1)]
     # adjoint, top layer down
     dnext = dtop.astype(jnp.float32)  # grad flowing into layer l's h output
     dwx_l, dwh_l, db_l, dh_new, dc_new = [], [], [], [], []
     for l in reversed(range(Lp)):
-        dx_l, dh_l, dc_l, dwx_c, dwh_c, db_c = lstm_cell_adjoint(
-            xs[l], h_in[l], c_in[l], wx[l], wh[l], b[l], dnext + dh[l], dc[l]
-        )
+        h_in, c_in, _, gates = layers[l]
+        cts = (dnext + dh[l], dc[l])
+        if gates is None:
+            adj = lstm_cell_adjoint(xs[l], h_in, c_in, wx[l], wh[l], b[l], *cts)
+        else:
+            adj = lstm_cell_gate_adjoint(xs[l], h_in, c_in, gates, wx[l], wh[l], *cts)
+        dx_l, dh_l, dc_l, dwx_c, dwh_c, db_c = adj
         dwx_l.append(dwx_c)
         dwh_l.append(dwh_c)
         db_l.append(db_c)
@@ -173,6 +206,15 @@ def _cell_fwd_bwd(wx, wh, b, first_in, h_in, c_in, dtop, dh, dc, *, cell, dt):
         stack_rev(dwh_l),
         stack_rev(db_l),
     )
+
+
+def _after_cotangent(x, lefts, dy):
+    """``(x, lefts, dy)`` with the residuals released only once ``dy``
+    exists.  The recompute pass reads nothing else of the step, so the
+    scheduler could otherwise start it, and its stash, as early as right
+    after the forward: beside the head's logits, the step's largest
+    buffer.  Tied to ``dy``, it starts after the head's backward."""
+    return jax.lax.optimization_barrier((x, lefts, dy))
 
 
 def _last_stage(outs, num_stages: int):
@@ -313,21 +355,21 @@ def _scheduled_pipeline(mesh: Mesh, sched: PipelineSchedule, *, model_axis: str,
             lg = jax.lax.dynamic_slice_in_dim(lefts, m0, g, axis=0)  # [g, S, B_mb, H]
             dyg = jax.lax.dynamic_slice_in_dim(dymb, m0, g, axis=0)  # [g, B_mb, S, H]
 
-            # phase A: recompute this group's forward, stashing ONLY the
-            # per-step recurrent carries — g*S token-steps live per stage,
-            # the schedule's liveness contract.
+            # phase A: recompute this group's forward, stashing each step's
+            # h/c outputs and the cells' gates — g*S token-steps
+            # live per stage, the schedule's liveness contract.
             def fstep(carry, j):
                 h, c = carry
                 mi, t = j // S, j % S
                 first_in = stage_input(xg, lg, mi, t)
                 h_in = jnp.where(t == 0, jnp.zeros_like(h), h)
                 c_in = jnp.where(t == 0, jnp.zeros_like(c), c)
-                hs, cs, _ = _stage_sweep(cell, Lp, first_in, h_in, c_in, dt=dt, in_max=in_max)
-                return (hs, cs), (h_in, c_in)
+                hs, cs, stash = _stage_sweep(cell, Lp, first_in, h_in, c_in, dt=dt, in_max=in_max)
+                return (hs, cs), stash
 
             h0 = vary(jnp.zeros((Lp, B_mb, hidden), jnp.float32))
             c0 = vary(jnp.zeros((Lp, B_mb, hidden), jnp.float32))
-            _, (h_ins, c_ins) = jax.lax.scan(fstep, (h0, c0), jnp.arange(G))
+            _, stash = jax.lax.scan(fstep, (h0, c0), jnp.arange(G))
 
             # phase B: the mirrored backward wavefront over the group, the
             # hand-off gradient ppermuted DOWN the stage chain each tick.
@@ -338,8 +380,7 @@ def _scheduled_pipeline(mesh: Mesh, sched: PipelineSchedule, *, model_axis: str,
                 vc = jnp.clip(v, 0, G - 1)
                 j = G - 1 - vc
                 mi, t = j // S, j % S
-                h_in = jax.lax.dynamic_index_in_dim(h_ins, j, axis=0, keepdims=False)
-                c_in = jax.lax.dynamic_index_in_dim(c_ins, j, axis=0, keepdims=False)
+                layers = _stashed_step(stash, j, t)
                 first_in = stage_input(xg, lg, mi, t)
                 dy_m = jax.lax.dynamic_index_in_dim(dyg, mi, axis=0, keepdims=False)
                 dy_t = jax.lax.dynamic_index_in_dim(dy_m, t, axis=1, keepdims=False)
@@ -350,8 +391,8 @@ def _scheduled_pipeline(mesh: Mesh, sched: PipelineSchedule, *, model_axis: str,
                 # the stage-top grad: the loss side for the last stage, the
                 # ppermuted hand-off grad from stage s+1 otherwise
                 dtop = jnp.where(stage == NS - 1, dy_t, dleft_in)
-                dfirst, dh_n, dc_n, dwx_c, dwh_c, db_c = _cell_fwd_bwd(
-                    wx, wh, b, first_in, h_in, c_in, dtop, dh_u, dc_u, cell=cell, dt=dt
+                dfirst, dh_n, dc_n, dwx_c, dwh_c, db_c = _stage_bwd(
+                    wx, wh, b, first_in, layers, dtop, dh_u, dc_u, dt=dt
                 )
                 vm = valid[None, None]
                 dh = jnp.where(vm, dh_n, dh)
@@ -416,7 +457,7 @@ def _scheduled_pipeline(mesh: Mesh, sched: PipelineSchedule, *, model_axis: str,
 
     def run_bwd(res, dy):
         stacked, x, lefts = res
-        return _run_bwd(stacked, x, lefts, dy)
+        return _run_bwd(stacked, *_after_cotangent(x, lefts, dy))
 
     run.defvjp(run_fwd, run_bwd)
     return run
@@ -555,26 +596,24 @@ def _interleaved_pipeline(mesh: Mesh, sched: PipelineSchedule, *, model_axis: st
 
         # phase A: recompute every chunk's forward from its saved boundary
         # inputs (chunks recompute independently — their couplings are all
-        # in the saved hand-offs), stashing the per-step carries.
+        # in the saved hand-offs), stashing each step's h/c outputs and
+        # gates.
         def fstep(carry, j):
             h, c = carry  # [v, Lc, B_mb, H]
             mi, t = j // S, j % S
-            hs_all, cs_all, h_ins, c_ins = [], [], [], []
+            sweeps = []
             for ci in range(v):
                 h_in = jnp.where(t == 0, jnp.zeros_like(h[ci]), h[ci])
                 c_in = jnp.where(t == 0, jnp.zeros_like(c[ci]), c[ci])
-                hs, cs, _ = _stage_sweep(
+                sweeps.append(_stage_sweep(
                     cells[ci], Lc, first_input(ci, mi, t), h_in, c_in, dt=dt, in_max=in_max
-                )
-                hs_all.append(hs)
-                cs_all.append(cs)
-                h_ins.append(h_in)
-                c_ins.append(c_in)
-            return (jnp.stack(hs_all), jnp.stack(cs_all)), (jnp.stack(h_ins), jnp.stack(c_ins))
+                ))
+            hs, cs, stash = zip(*sweeps)
+            return (jnp.stack(hs), jnp.stack(cs)), stash
 
         h0 = vary(jnp.zeros((v, Lc, B_mb, hidden), jnp.float32))
         c0 = vary(jnp.zeros((v, Lc, B_mb, hidden), jnp.float32))
-        _, (h_ins, c_ins) = jax.lax.scan(fstep, (h0, c0), jnp.arange(G))  # [G, v, Lc, B, H]
+        _, stash = jax.lax.scan(fstep, (h0, c0), jnp.arange(G))  # per chunk, per layer
 
         # phase B: the mirrored VS-deep backward wavefront on the ring.
         def bstep(carry, taub):
@@ -587,16 +626,15 @@ def _interleaved_pipeline(mesh: Mesh, sched: PipelineSchedule, *, model_axis: st
                 vcl = jnp.clip(vb, 0, G - 1)
                 j = G - 1 - vcl
                 mi, t = j // S, j % S
-                h_in = jax.lax.dynamic_index_in_dim(h_ins, j, axis=0, keepdims=False)[ci]
-                c_in = jax.lax.dynamic_index_in_dim(c_ins, j, axis=0, keepdims=False)[ci]
+                layers = _stashed_step(stash[ci], j, t)
                 dy_m = jax.lax.dynamic_index_in_dim(dymb, mi, axis=0, keepdims=False)
                 dy_t = jax.lax.dynamic_index_in_dim(dy_m, t, axis=1, keepdims=False)
                 dh_u = jnp.where(t == S - 1, jnp.zeros_like(dh[ci]), dh[ci])
                 dc_u = jnp.where(t == S - 1, jnp.zeros_like(dc[ci]), dc[ci])
                 dtop = jnp.where(vs == VS - 1, dy_t, dleft_in[ci])
-                dfirst, dh_n, dc_n, dwx_c, dwh_c, db_c = _cell_fwd_bwd(
-                    wx[ci], wh[ci], b[ci], first_input(ci, mi, t), h_in, c_in,
-                    dtop, dh_u, dc_u, cell=cells[ci], dt=dt,
+                dfirst, dh_n, dc_n, dwx_c, dwh_c, db_c = _stage_bwd(
+                    wx[ci], wh[ci], b[ci], first_input(ci, mi, t), layers,
+                    dtop, dh_u, dc_u, dt=dt,
                 )
                 vm = valid[None, None]
                 dh_new.append(jnp.where(vm, dh_n, dh[ci]))
@@ -662,7 +700,7 @@ def _interleaved_pipeline(mesh: Mesh, sched: PipelineSchedule, *, model_axis: st
 
     def run_bwd(res, dy):
         stacked, x, lefts = res
-        return _run_bwd(stacked, x, lefts, dy)
+        return _run_bwd(stacked, *_after_cotangent(x, lefts, dy))
 
     run.defvjp(run_fwd, run_bwd)
     return run

@@ -62,7 +62,11 @@ Two more instances (the PR 4 follow-ups):
 The table models the parallel-hardware timeline (what NS devices would
 execute).  The single-program executor in ``core/pipeline.py`` realizes
 the same dependency order with the same liveness bound via per-group
-recompute; see its module docstring for the exact correspondence.
+recompute; see its module docstring for the exact correspondence.  What
+one live token-step holds there is what its recompute pass produced:
+each layer's h and c outputs and, for a cell that exposes them, its four
+gate pre-activations, so the backward computes no gate a second time
+(``hybrid.pipeline_activation_model`` prices the unit).
 """
 from __future__ import annotations
 

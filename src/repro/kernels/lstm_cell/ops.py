@@ -2,9 +2,10 @@
 
 ``lstm_cell_fused`` is differentiable: the forward runs the Pallas kernel
 (compiled on TPU, interpret mode on CPU) and a ``jax.custom_vjp`` supplies
-the analytic LSTM-cell backward in fp32 jnp — the flash-style recompute
-backward (gates rebuilt from the saved inputs, no activation stash) is the
-schedule a fused backward kernel would implement anyway.  The backward's
+the analytic LSTM-cell backward in fp32 jnp.  The kernel returns only
+(h', c'), so its backward rebuilds the gates from the saved inputs (the
+flash-style recompute) and hands them to the same gate adjoint the
+wavefront's backward applies to the gates it stashed.  The backward's
 parity against ``jax.grad`` of ``lstm_cell_ref`` is pinned by
 ``tests/test_kernels.py``.
 """
@@ -28,18 +29,19 @@ def _gates(x, h, wx, wh, b):
     )
 
 
-def lstm_cell_adjoint(x, h, c, wx, wh, b, dh_new, dc_new):
-    """Analytic fp32 adjoint of one LSTM cell, gates recomputed from the
-    saved inputs (the flash-style recompute schedule — no activation
-    stash).  The single source of truth for the cell's backward math:
-    consumed by the fused kernel's custom-vjp below AND by the pipeline's
-    scheduled backward (``core/pipeline.py``).
+def lstm_cell_gate_adjoint(x, h, c, gates, wx, wh, dh_new, dc_new):
+    """Analytic fp32 adjoint of one LSTM cell from its fp32 pre-activation
+    gates [B, 4, H] as the forward computed them: no gate GEMM, only the
+    four the gradient needs.  The single source of truth for the cell's
+    backward math: the wavefront's backward (``core/pipeline.py``) feeds it
+    the gates its recompute pass stashed, and :func:`lstm_cell_adjoint`
+    the gates it rebuilds.
 
     (x [B, In], h/c [B, H] previous state, dh_new/dc_new cotangents of the
     new state, all any dtype) -> fp32 (dx, dh, dc, dwx, dwh, db)."""
     dh_new = dh_new.astype(jnp.float32)
     dc_new = dc_new.astype(jnp.float32)
-    gates = _gates(x, h, wx, wh, b)
+    gates = gates.astype(jnp.float32)
     i_s = jax.nn.sigmoid(gates[:, 0])
     f_s = jax.nn.sigmoid(gates[:, 1])
     g_t = jnp.tanh(gates[:, 2])
@@ -65,6 +67,16 @@ def lstm_cell_adjoint(x, h, c, wx, wh, b, dh_new, dc_new):
     dwh = jnp.einsum("bj,bgh->jgh", h.astype(jnp.float32), d_pre)
     db = d_pre.sum(axis=0)
     return dx, dh, dc, dwx, dwh, db
+
+
+def lstm_cell_adjoint(x, h, c, wx, wh, b, dh_new, dc_new):
+    """Analytic fp32 adjoint of one LSTM cell, gates recomputed from the
+    saved inputs (for a forward that kept none, as the fused kernel's):
+    the fp32 gate GEMMs, then :func:`lstm_cell_gate_adjoint`.
+
+    (x [B, In], h/c [B, H] previous state, dh_new/dc_new cotangents of the
+    new state, all any dtype) -> fp32 (dx, dh, dc, dwx, dwh, db)."""
+    return lstm_cell_gate_adjoint(x, h, c, _gates(x, h, wx, wh, b), wx, wh, dh_new, dc_new)
 
 
 @functools.lru_cache(maxsize=None)

@@ -388,6 +388,47 @@ def analyze_hlo(text: str, fallback_trip: int = 1, detail: bool = False) -> HloS
     return stats
 
 
+_CALLED_RE = re.compile(r"(?:calls|to_apply)=%?([\w\.\-]+)|branch_computations=\{([^}]*)\}")
+
+
+def _called(line: str) -> list:
+    """Computations an instruction calls (fusion, call, reduce, branches)."""
+    out = []
+    for m in _CALLED_RE.finditer(line):
+        out += [m.group(1)] if m.group(1) else [n.strip().lstrip("%") for n in m.group(2).split(",")]
+    return out
+
+
+def loop_gemm_counts(text: str) -> list:
+    """GEMMs (``dot`` and ``convolution`` instructions) in one iteration of
+    each outermost ``while`` loop of the module, in text order: the body's
+    own and those of what it calls (fusions, calls, branches), not those of
+    loops nested inside it."""
+    comps = _split_computations(text)
+
+    def lines(name: str) -> list:
+        return comps.get(name, {"lines": []})["lines"]
+
+    def gemms(name: str) -> int:
+        n = 0
+        for line in lines(name):
+            if _WHILE_RE.search(line):
+                continue
+            d = _DEF_RE.match(line)
+            n += bool(d and d.group(3) in ("dot", "convolution"))
+            n += sum(gemms(c) for c in _called(line))
+        return n
+
+    def loops(name: str) -> list:
+        out = []
+        for line in lines(name):
+            wm = _WHILE_RE.search(line)
+            out += [gemms(wm.group(2))] if wm else [c for callee in _called(line) for c in loops(callee)]
+        return out
+
+    return loops(next(n for n, c in comps.items() if c["entry"]))
+
+
 def parse_collective_bytes(text: str, fallback_trip: int = 1) -> Tuple[Dict[str, float], float]:
     """Back-compat wrapper: ({type: per-device bytes}, total)."""
     s = analyze_hlo(text, fallback_trip)

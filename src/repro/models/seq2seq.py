@@ -98,6 +98,18 @@ def attention_softmax_head(head, S: jax.Array, H: jax.Array, src_mask: jax.Array
 # ---------------------------------------------------------------------------
 
 
+@jax.custom_vjp
+def _grads_together(tree):
+    """Identity whose cotangents are released together: what the head
+    sends back to the backbones waits for the head's own parameter
+    gradients, the logits' last readers, so the logits are freed before
+    the backbones' backward passes allocate what they stash."""
+    return tree
+
+
+_grads_together.defvjp(lambda tree: (tree, None), lambda _, ct: (jax.lax.optimization_barrier(ct),))
+
+
 def forward_no_input_feeding(
     params,
     cfg: ModelConfig,
@@ -132,7 +144,8 @@ def forward_no_input_feeding(
         S, H = phase_boundary(S), phase_boundary(H)
     # ---- phase 2: data-parallel attention-softmax ----------------------
     with jax.named_scope("head"):
-        _, logits = attention_softmax_head(params["head"], S, H, batch.src_mask, stage_kernel=stage_kernel)
+        head, S, H = _grads_together((params["head"], S, H))
+        _, logits = attention_softmax_head(head, S, H, batch.src_mask, stage_kernel=stage_kernel)
         loss, denom = softmax_cross_entropy(logits, batch.tgt_out, batch.tgt_mask)
     return loss, {"logits": logits, "denom": denom}
 

@@ -7,6 +7,8 @@ import subprocess
 import sys
 import textwrap
 
+import pytest
+
 
 ROOT = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
 SRC = os.path.join(ROOT, "src")
@@ -75,17 +77,25 @@ def test_pipeline_equals_sequential_and_grad():
         params, _ = lstm.init_stacked_lstm(ini, "enc", L, e, h)
         x = jax.random.normal(jax.random.key(1), (B, S, e), jnp.float32)
         ref = np.array(lstm.run_stacked_lstm(params, x)[0])
+        gref = jax.grad(lambda p: lstm.run_stacked_lstm(p, x)[0].sum())(params)
         with jax.set_mesh(mesh):
             stacked, _ = pl.stack_pipeline_params(params, 4)  # 2 layers / stage
             out = np.array(jax.jit(lambda st_, xx: pl.pipeline_lstm(mesh, st_, xx, in_dim=e))(stacked, x))
             g = jax.jit(jax.grad(lambda st_: pl.pipeline_lstm(mesh, st_, x, in_dim=e).sum()))(stacked)
             gs = float(jnp.abs(g["wx"]).sum())
-        print(json.dumps({"err": float(np.abs(out - ref).max()), "gsum": gs}))
+        gerr = 0.0
+        for li, pref in enumerate(gref):
+            s_, l_ = li // 2, li % 2
+            for name in ("wx", "wh", "b"):
+                n = pref[name].shape[0]
+                gerr = max(gerr, float(jnp.abs(g[name][s_, l_, :n] - pref[name]).max()))
+        print(json.dumps({"err": float(np.abs(out - ref).max()), "gsum": gs, "gerr": gerr}))
         """
     )
     res = _run(code)
     assert res["err"] < 1e-5
     assert res["gsum"] > 0
+    assert res["gerr"] < 2e-4, res  # the gate-stash backward = autodiff of the scans
 
 
 def test_pipeline_microbatched_wavefront_matches_sequential():
@@ -144,21 +154,24 @@ def test_pipeline_microbatched_wavefront_matches_sequential():
         assert r["naive_absent"] == 1, (k, r)
 
 
-def test_pipeline_schedule_grads_match_sequential_multistage():
-    """The schedule-driven custom-vjp backward on a REAL 4-stage pipeline:
-    for gpipe and 1f1b at k in (1, 2, 4) — plus zerobubble and interleaved
-    (v=2, 1 layer/chunk) at the fully pipelined k=4 point — outputs AND
-    parameter/input grads match the sequential reference: the mirrored
-    backward wavefront's ppermute chain, the interleaved ring, and the
-    per-group recompute are numerically exact."""
+def _schedule_grad_errors(mesh_shape, layers, cases, stage_kernel="jnp") -> dict:
+    """Run ``pipeline_lstm`` on a ``mesh_shape`` ("data", "model") mesh of
+    host devices for each (schedule, micro_batches, virtual_stages) case
+    and return, per case, its worst output, parameter-grad and input-grad
+    gaps to autodiff of the plain ``run_stacked_lstm``."""
     code = PREAMBLE + textwrap.dedent(
-        """
+        f"""
         from repro.models import lstm
         from repro.models.common import Initializer
         from repro.core import pipeline as pl
-        mesh = make_mesh((2, 4), ("data", "model"))
+        mesh_shape, L, cases, sk = {mesh_shape!r}, {layers!r}, {cases!r}, {stage_kernel!r}
+        """
+    ) + textwrap.dedent(
+        """
+        mesh = make_mesh(mesh_shape, ("data", "model"))
+        NS = mesh_shape[1]
         ini = Initializer(jax.random.key(0))
-        L, e, h, B, S = 8, 24, 32, 8, 13
+        e, h, B, S = 24, 32, 8, 13
         params, _ = lstm.init_stacked_lstm(ini, "enc", L, e, h)
         x = jax.random.normal(jax.random.key(1), (B, S, e), jnp.float32)
         ref_y = lstm.run_stacked_lstm(params, x)[0]
@@ -168,38 +181,71 @@ def test_pipeline_schedule_grads_match_sequential_multistage():
         )(params, x)
         res = {}
         with jax.set_mesh(mesh):
-            stacked, _ = pl.stack_pipeline_params(params, 4)  # 2 layers/stage
-            for k in (1, 2, 4):
-                kinds = [("gpipe", 1), ("1f1b", 1)]
-                if k == 4:
-                    kinds += [("zerobubble", 1), ("interleaved", 2)]
-                for sched, vs in kinds:
-                    fn = lambda st_, xx: pl.pipeline_lstm(
-                        mesh, st_, xx, in_dim=e, micro_batches=k,
-                        schedule=sched, virtual_stages=vs)
-                    y = jax.jit(fn)(stacked, x)
-                    g, gx = jax.jit(jax.grad(
-                        lambda st_, xx: (fn(st_, xx) * w).sum(), argnums=(0, 1)))(stacked, x)
-                    gerr = 0.0
-                    for li, pref in enumerate(gref):
-                        s_, l_ = li // 2, li % 2
-                        gerr = max(gerr, float(jnp.abs(g["wh"][s_, l_] - pref["wh"]).max()))
-                        gerr = max(gerr, float(jnp.abs(g["b"][s_, l_] - pref["b"]).max()))
-                        nwx = pref["wx"].shape[0]
-                        gerr = max(gerr, float(jnp.abs(g["wx"][s_, l_, :nwx] - pref["wx"]).max()))
-                    res[f"{sched}_k{k}"] = {
-                        "yerr": float(jnp.abs(y - ref_y).max()),
-                        "gerr": gerr,
-                        "gxerr": float(jnp.abs(gx - gxref).max()),
-                    }
+            stacked, _ = pl.stack_pipeline_params(params, NS)
+            Lp = L // NS
+            for sched, k, vs in cases:
+                fn = lambda st_, xx: pl.pipeline_lstm(
+                    mesh, st_, xx, in_dim=e, micro_batches=k,
+                    schedule=sched, virtual_stages=vs, stage_kernel=sk)
+                y = jax.jit(fn)(stacked, x)
+                g, gx = jax.jit(jax.grad(
+                    lambda st_, xx: (fn(st_, xx) * w).sum(), argnums=(0, 1)))(stacked, x)
+                gerr = 0.0
+                for li, pref in enumerate(gref):
+                    s_, l_ = li // Lp, li % Lp
+                    gerr = max(gerr, float(jnp.abs(g["wh"][s_, l_] - pref["wh"]).max()))
+                    gerr = max(gerr, float(jnp.abs(g["b"][s_, l_] - pref["b"]).max()))
+                    nwx = pref["wx"].shape[0]
+                    gerr = max(gerr, float(jnp.abs(g["wx"][s_, l_, :nwx] - pref["wx"]).max()))
+                res[f"{sched}_k{k}"] = {
+                    "yerr": float(jnp.abs(y - ref_y).max()),
+                    "gerr": gerr,
+                    "gxerr": float(jnp.abs(gx - gxref).max()),
+                }
         print(json.dumps(res))
         """
     )
-    res = _run(code)
+    return _run(code, devices=mesh_shape[0] * mesh_shape[1])
+
+
+def _assert_schedule_grads(res: dict):
     for name, r in res.items():
         assert r["yerr"] < 1e-5, (name, r)
         assert r["gerr"] < 2e-4, (name, r)
         assert r["gxerr"] < 1e-4, (name, r)
+
+
+# every schedule kind, at 1 and 2 microbatches (interleaved with 2 chunks)
+_ALL_KINDS = [(kind, k, 2 if kind == "interleaved" else 1)
+              for kind in ("gpipe", "1f1b", "zerobubble", "interleaved") for k in (1, 2)]
+
+
+def test_pipeline_schedule_grads_match_sequential_multistage():
+    """The schedule-driven custom-vjp backward on a REAL 4-stage pipeline:
+    for gpipe and 1f1b at k in (1, 2, 4), and zerobubble and interleaved
+    (v=2, 1 layer/chunk) at k in (1, 2, 4), outputs AND parameter/input
+    grads match the sequential reference: the mirrored backward wavefront's
+    ppermute chain, the interleaved ring, the per-group recompute and the
+    backward from its gate stash are numerically exact."""
+    cases = _ALL_KINDS + [(kind, 4, 2 if kind == "interleaved" else 1)
+                          for kind in ("gpipe", "1f1b", "zerobubble", "interleaved")]
+    _assert_schedule_grads(_schedule_grad_errors((2, 4), 8, cases))
+
+
+@pytest.mark.parametrize("mesh_shape,layers,stage_kernel", [
+    pytest.param((1, 4), 8, "jnp", id="hybrid14-jnp"),
+    pytest.param((1, 4), 8, "pallas", id="hybrid14-pallas"),
+    pytest.param((1, 1), 4, "jnp", id="single-jnp"),
+    pytest.param((1, 1), 4, "pallas", id="single-pallas"),
+])
+def test_pipeline_gate_stash_grads_match_autodiff(mesh_shape, layers, stage_kernel):
+    """The backward from the recompute pass's stash — the jnp cell's
+    stashed gates, or the fused Pallas cell's rebuilt ones — equals
+    autodiff of the plain ``run_stacked_lstm`` for every schedule kind at
+    k in (1, 2): on the (1, 4) hybrid mesh of four host devices (2 layers
+    a stage, the four-chip layout) and on one device (all layers in one
+    stage)."""
+    _assert_schedule_grads(_schedule_grad_errors(mesh_shape, layers, _ALL_KINDS, stage_kernel))
 
 
 def test_train_step_plan_microbatched_pipeline_runs_sharded():

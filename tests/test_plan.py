@@ -289,6 +289,22 @@ def _fixed_batch(cfg, B=8, M=12, N=10):
     }
 
 
+def _assert_grads_match(ref, got):
+    """Every grad leaf of ``got`` allclose to ``ref`` at fp32."""
+    flat_r, tree_r = jax.tree.flatten(ref)
+    flat_g, tree_g = jax.tree.flatten(got)
+    assert tree_r == tree_g
+    for a, b in zip(flat_r, flat_g):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5, rtol=1e-4)
+
+
+def _autodiff_reference(cfg, strat, mesh, params, batch, rng):
+    """Loss and grads of the unpipelined plan: autodiff of the plain
+    ``run_stacked_lstm`` scans, what the wavefront's backward must equal."""
+    loss, _, grads = jax.jit(make_grad_fn(cfg, ExecutionPlan(strategy=strat, mesh=mesh)))(params, batch, rng)
+    return loss, grads
+
+
 @pytest.mark.parametrize("strat", [st.Strategy.HYBRID, st.Strategy.MODEL])
 def test_plan_microbatch_matches_reference(strat):
     """Loss/grads from ExecutionPlan(micro_batches=k) — both the wavefront
@@ -344,31 +360,42 @@ def test_overlap_grad_sync_is_pure_reordering():
 
 
 @pytest.mark.pallas
-@pytest.mark.parametrize("strat", [st.Strategy.HYBRID, st.Strategy.MODEL])
-def test_pipelined_train_step_stage_kernel_parity(strat):
+@pytest.mark.parametrize("strat,schedule,k", [
+    pytest.param(st.Strategy.HYBRID, "gpipe", 2, id="hybrid"),
+    pytest.param(st.Strategy.MODEL, "gpipe", 2, id="model"),
+    pytest.param(st.Strategy.HYBRID, "gpipe", 1, id="hybrid-gpipe-k1"),
+    pytest.param(st.Strategy.HYBRID, "1f1b", 2, id="hybrid-1f1b-k2"),
+    pytest.param(st.Strategy.HYBRID, "zerobubble", 1, id="hybrid-zerobubble-k1"),
+    pytest.param(st.Strategy.HYBRID, "interleaved", 2, id="hybrid-interleaved-k2"),
+])
+def test_pipelined_train_step_stage_kernel_parity(strat, schedule, k):
     """A pipelined train step with stage_kernel="pallas" (the
-    fused LSTM cell kernel, interpreted on CPU) matches the "jnp" path:
-    loss and every grad leaf allclose at fp32.  This is the guarantee that
-    wiring the kernel into the hot path can never silently diverge."""
+    fused LSTM cell kernel, interpreted on CPU) matches the "jnp" path,
+    and both match autodiff of the plain ``run_stacked_lstm``: loss and
+    every grad leaf allclose at fp32.  The jnp cell's backward runs from
+    the gates its recompute pass stashed, the Pallas cell's rebuilds them,
+    so this is the guarantee that the two backward paths and the kernel
+    in the hot path can never silently diverge."""
     cfg = dataclasses.replace(get_config("seq2seq-rnn", smoke=True), dropout=0.0, dtype="float32")
     params, _ = s2s.init_seq2seq(jax.random.key(0), cfg)
     batch = _fixed_batch(cfg, B=4, M=8, N=6)
     mesh = make_mesh((1, 1), ("data", "model"))
     rng = jax.random.key(3)
+    vs = 2 if schedule == "interleaved" else 1
 
+    loss_ref, g_ref = _autodiff_reference(cfg, strat, mesh, params, batch, rng)
     losses, grads = {}, {}
     for sk in ("jnp", "pallas"):
         plan = ExecutionPlan(
-            strategy=strat, mesh=mesh, micro_batches=2, use_pipeline=True, stage_kernel=sk
+            strategy=strat, mesh=mesh, micro_batches=k, use_pipeline=True, stage_kernel=sk,
+            schedule=schedule, virtual_stages=vs,
         )
         assert plan.pipelined
         losses[sk], _, grads[sk] = jax.jit(make_grad_fn(cfg, plan))(params, batch, rng)
     assert abs(float(losses["jnp"]) - float(losses["pallas"])) < 1e-5
-    flat_j, tree_j = jax.tree.flatten(grads["jnp"])
-    flat_p, tree_p = jax.tree.flatten(grads["pallas"])
-    assert tree_j == tree_p
-    for a, b in zip(flat_j, flat_p):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5, rtol=1e-4)
+    assert abs(float(losses["jnp"]) - float(loss_ref)) < 1e-5
+    _assert_grads_match(grads["jnp"], grads["pallas"])
+    _assert_grads_match(g_ref, grads["jnp"])
 
 
 # ---------------------------------------------------------------------------
@@ -428,18 +455,25 @@ def test_schedule_1f1b_stash_bound_and_gpipe_identity():
 
 
 @pytest.mark.pipeline
-@pytest.mark.parametrize("strat", [st.Strategy.HYBRID, st.Strategy.MODEL])
-def test_pipelined_train_step_schedule_parity(strat):
+@pytest.mark.parametrize("strat,k", [
+    pytest.param(st.Strategy.HYBRID, 4, id="hybrid"),
+    pytest.param(st.Strategy.MODEL, 4, id="model"),
+    pytest.param(st.Strategy.HYBRID, 1, id="hybrid-k1"),
+    pytest.param(st.Strategy.HYBRID, 2, id="hybrid-k2"),
+])
+def test_pipelined_train_step_schedule_parity(strat, k):
     """Train-step gradient parity gpipe vs 1f1b (fp32): the 1F1B backward
     is a pure reordering of the same per-microbatch gradient sums, so loss
     and every grad leaf must agree — while the schedule table certifies the
-    1f1b stash stays within min(k, NS) microbatches per stage."""
+    1f1b stash stays within min(k, NS) microbatches per stage.  Both equal
+    autodiff of the plain ``run_stacked_lstm``: the backward from the
+    recompute pass's gate stash is the scan's exact gradient."""
     cfg = dataclasses.replace(get_config("seq2seq-rnn", smoke=True), dropout=0.0, dtype="float32")
     params, _ = s2s.init_seq2seq(jax.random.key(0), cfg)
     batch = _fixed_batch(cfg)
     mesh = make_mesh((1, 1), ("data", "model"))
     rng = jax.random.key(11)
-    k = 4
+    loss_ref, g_ref = _autodiff_reference(cfg, strat, mesh, params, batch, rng)
     losses, grads = {}, {}
     for kind in ("gpipe", "1f1b"):
         plan = ExecutionPlan(
@@ -453,12 +487,10 @@ def test_pipelined_train_step_schedule_parity(strat):
         else:
             assert peak == k
         losses[kind], _, grads[kind] = jax.jit(make_grad_fn(cfg, plan))(params, batch, rng)
+        assert abs(float(losses[kind]) - float(loss_ref)) < 1e-5, kind
+        _assert_grads_match(g_ref, grads[kind])
     assert abs(float(losses["gpipe"]) - float(losses["1f1b"])) < 1e-5
-    flat_g, tree_g = jax.tree.flatten(grads["gpipe"])
-    flat_o, tree_o = jax.tree.flatten(grads["1f1b"])
-    assert tree_g == tree_o
-    for a, b in zip(flat_g, flat_o):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5, rtol=1e-4)
+    _assert_grads_match(grads["gpipe"], grads["1f1b"])
 
 
 @pytest.mark.pipeline
@@ -504,6 +536,45 @@ def test_pipeline_activation_model_1f1b_bounds_memory():
         assert ob[k] <= gp[k]
         assert ob[k] <= min(k, NS) * ob[1] + 1e-9  # the table's depth bound
     assert ob[16] == ob[8]  # saturated: flat in k past the pipeline depth
+    # one unit: each layer's h and c and the jnp cell's four gate
+    # pre-activations, fp32; the fused Pallas cell's backward rebuilds them
+    unit = lambda sk: pipeline_activation_model(
+        cfg, schedule="gpipe", micro_batches=1, batch=B_mb, stage_kernel=sk, **kw)["unit_bytes"]
+    lp = cfg.num_layers // NS
+    assert unit("jnp") == (4 + 2) * lp * B_mb * cfg.d_model * 4
+    assert unit("pallas") == 2 * lp * B_mb * cfg.d_model * 4
+
+
+@pytest.mark.pipeline
+@pytest.mark.parametrize("stage_kernel", ["jnp", "pallas"])
+def test_pipeline_backward_gemm_count(stage_kernel):
+    """GEMMs per iteration of each loop of the compiled step
+    (``hlo_analysis.loop_gemm_counts``), 4 layers on a (1, 1) mesh (Lp =
+    4): per side, the forward scan and the recompute pass (phase A) run the
+    2·Lp gate GEMMs, and the backward loop (phase B) runs from the jnp
+    cell's stashed gates, so only the gradient's 4·Lp (dx, dh, dWx, dWh).
+    The fused Pallas cell keeps its gates, so its phase B rebuilds them:
+    6·Lp (the kernel's own GEMMs sit in its interpreted grid loops, nested
+    and not counted)."""
+    from repro.launch.hlo_analysis import loop_gemm_counts
+
+    Lp = 4
+    cfg = dataclasses.replace(
+        get_config("seq2seq-rnn", smoke=True), num_layers=Lp, dropout=0.0, dtype="float32"
+    )
+    params, _ = s2s.init_seq2seq(jax.random.key(0), cfg)
+    batch = _fixed_batch(cfg, B=4, M=6, N=5)
+    plan = ExecutionPlan(
+        strategy=st.Strategy.HYBRID, mesh=make_mesh((1, 1), ("data", "model")),
+        use_pipeline=True, stage_kernel=stage_kernel,
+    )
+    compiled = jax.jit(make_grad_fn(cfg, plan)).lower(params, batch, jax.random.key(0)).compile()
+    counts = sorted(loop_gemm_counts(compiled.as_text()))
+    if stage_kernel == "jnp":
+        assert counts == [2 * Lp] * 4 + [4 * Lp] * 2, counts
+    else:
+        assert counts[-2:] == [6 * Lp] * 2, counts
+        assert 4 * Lp not in counts, counts
 
 
 @pytest.mark.pipeline
@@ -535,22 +606,28 @@ def test_interleaved_v1_is_gpipe():
 
 
 @pytest.mark.pipeline
-@pytest.mark.parametrize("strat", [st.Strategy.HYBRID, st.Strategy.MODEL])
-def test_pipelined_train_step_new_schedule_parity(strat):
+@pytest.mark.parametrize("strat,k", [
+    pytest.param(st.Strategy.HYBRID, 4, id="hybrid"),
+    pytest.param(st.Strategy.MODEL, 4, id="model"),
+    pytest.param(st.Strategy.HYBRID, 1, id="hybrid-k1"),
+    pytest.param(st.Strategy.HYBRID, 2, id="hybrid-k2"),
+])
+def test_pipelined_train_step_new_schedule_parity(strat, k):
     """zerobubble and interleaved (v=2) execute a pure reordering of the
     same per-microbatch gradient sums: loss and every grad leaf must match
-    the gpipe execution within fp32 reordering noise."""
+    the gpipe execution, and autodiff of the plain ``run_stacked_lstm``,
+    within fp32 reordering noise."""
     cfg = dataclasses.replace(get_config("seq2seq-rnn", smoke=True), dropout=0.0, dtype="float32")
     params, _ = s2s.init_seq2seq(jax.random.key(0), cfg)
     batch = _fixed_batch(cfg)
     mesh = make_mesh((1, 1), ("data", "model"))
     rng = jax.random.key(17)
-    k = 4
     plans = {
         "gpipe": dict(schedule="gpipe"),
         "zerobubble": dict(schedule="zerobubble"),
         "interleaved_v2": dict(schedule="interleaved", virtual_stages=2),
     }
+    loss_ref, g_ref = _autodiff_reference(cfg, strat, mesh, params, batch, rng)
     losses, grads = {}, {}
     for name, kw in plans.items():
         plan = ExecutionPlan(
@@ -561,6 +638,9 @@ def test_pipelined_train_step_new_schedule_parity(strat):
         assert abs(float(losses["gpipe"]) - float(losses[name])) < 1e-5, name
         for a, b in zip(jax.tree.leaves(grads["gpipe"]), jax.tree.leaves(grads[name])):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5, rtol=1e-4)
+    for name in plans:
+        assert abs(float(losses[name]) - float(loss_ref)) < 1e-5, name
+        _assert_grads_match(g_ref, grads[name])
 
 
 @pytest.mark.pipeline
